@@ -1,7 +1,7 @@
 """Roofline decision gate for the Pallas kernels (beat-XLA-or-delete).
 
 For every kernel x wired call-site this compares, on the TPU roofline
-(launch/hlo_analysis.roofline_terms constants):
+(launch/hlo_analysis.PEAKS for a TPU v5e):
 
 - **baseline**: the pure-jnp reference math the call site would otherwise
   run, measured with XLA's own ``cost_analysis()`` (FLOPs + bytes accessed
@@ -43,7 +43,8 @@ BYTES = 4  # gate accounting runs both sides in f32
 
 
 def _roof(flops, byts):
-    r = roofline_terms({"flops": flops, "bytes accessed": byts}, {"total": 0.0}, 1)
+    r = roofline_terms({"flops": flops, "bytes accessed": byts}, {"total": 0.0},
+                       1, device_kind="TPU v5 lite")
     t = max(r["t_compute_s"], r["t_memory_s"])
     return t, ("compute" if r["t_compute_s"] >= r["t_memory_s"] else "memory")
 

@@ -108,7 +108,6 @@ def _bench_dispatch(rows, *, window=20, reps=5):
 _MESH2D_BENCH = """
 import dataclasses, time, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs import get_smoke_config
 from repro.models import backbones as bb
 from repro.models import sharding as shd
@@ -169,10 +168,10 @@ def bench(name, mesh_shape, compress):
                                                   build_batch(traj, v_last))
             return params, opt_state, jax.lax.pmean(m["loss"], "data")
         ts_spec = cross_replica_specs("data") if compress else P()
-        fn0 = jax.jit(shard_map(window, mesh=mesh,
-                                in_specs=(P(), ts_spec, P(), P("data")),
-                                out_specs=(P(), ts_spec, P()),
-                                check_rep=False, auto=frozenset({"model"})))
+        fn0 = jax.jit(jax.shard_map(window, mesh=mesh,
+                                    in_specs=(P(), ts_spec, P(), P("data")),
+                                    out_specs=(P(), ts_spec, P()),
+                                    check_vma=False, axis_names={"data"}))
         sid = jnp.arange(n_data, dtype=jnp.uint32)
         fn = lambda p, o, ks: fn0(p, o, ks, sid)
         opt_state = opt.init(params)
@@ -203,6 +202,7 @@ def _mesh2d_rows(n_devices: int = 4):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices; the chip stays free
     env["PYTHONPATH"] = os.path.join(repo, "src")
     r = subprocess.run([sys.executable, "-c", _MESH2D_BENCH],
                        capture_output=True, text=True, env=env, timeout=900)

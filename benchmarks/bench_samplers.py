@@ -92,6 +92,7 @@ def _sharded_rows(n_devices: int = 0):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices; the chip stays free
     env["PYTHONPATH"] = os.path.join(repo, "src")
     r = subprocess.run([sys.executable, "-c", _SHARDED_BENCH],
                        capture_output=True, text=True, env=env, timeout=900)

@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """§Perf hillclimb driver: measure a cell with cfg overrides, print the
 three roofline terms.  Usage:
   PYTHONPATH=src python -m benchmarks.perf_experiments A1 C1 B1
@@ -8,7 +6,7 @@ import json
 import sys
 
 from repro.models.config import SHAPES
-from repro.launch.dryrun import run_cell
+from repro.launch.dryrun import force_host_devices, run_cell
 
 CELLS = {c.name: c for c in SHAPES}
 
@@ -36,6 +34,7 @@ EXPERIMENTS = {
 }
 
 if __name__ == "__main__":
+    force_host_devices()
     for key in sys.argv[1:]:
         arch, shape, ov, tag = EXPERIMENTS[key]
         r = run_cell(arch, CELLS[shape], multi_pod=False, cfg_overrides=ov,

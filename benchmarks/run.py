@@ -22,6 +22,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (bench_samplers, bench_replay, bench_gae, bench_serving,
                    bench_learning, bench_r2d1, bench_kernels, bench_telemetry,
                    bench_async)

@@ -17,6 +17,7 @@ from repro.models.rl_models import make_q_conv
 from repro.samplers import SerialSampler
 from repro.runners import OffPolicyRunner
 from repro.train.optim import adam
+from repro.utils.compile_cache import enable_compile_cache
 
 VARIANTS = {
     "dqn": dict(double=False, dueling=False, n_atoms=0, prioritized=False),
@@ -29,6 +30,7 @@ VARIANTS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", choices=sorted(VARIANTS), default="rainbow")
     ap.add_argument("--iters", type=int, default=150)

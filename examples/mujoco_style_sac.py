@@ -18,9 +18,11 @@ from repro.samplers import SerialSampler
 from repro.runners import AsyncRunner
 from repro.replay.host import TransitionSamples, UniformReplayBuffer
 from repro.train.optim import adam
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=150)
     ap.add_argument("--replay-ratio", type=float, default=8.0)

@@ -20,9 +20,11 @@ from repro.samplers import EvalSampler, SerialSampler
 from repro.runners import OnPolicyRunner
 from repro.train.optim import adam
 from repro.utils.logger import Logger
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(log_dir="logs/quickstart"):
+    enable_compile_cache()
     env = make_env("cartpole")
     model = make_pg_mlp(obs_dim=4, n_actions=2)
     agent = make_categorical_pg_agent(model)

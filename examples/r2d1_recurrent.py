@@ -18,9 +18,11 @@ from repro.samplers import AlternatingSampler
 from repro.runners import AsyncR2D1Runner
 from repro.replay.host import SequenceSamples, SequenceReplayBuffer
 from repro.train.optim import adam
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=120)
     ap.add_argument("--replay-ratio", type=float, default=2.0)

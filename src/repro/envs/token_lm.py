@@ -6,6 +6,10 @@ is the log-probability of that transition under the chain (so the optimal
 policy matches the chain's conditional argmax, and expected reward has a known
 upper bound).  Batched action selection over this env is exactly LM decoding;
 the paper's serving machinery runs unchanged.
+
+Each token's row of transition logits is drawn from its own key when the
+env steps, so a full-size vocabulary never materializes the (V, V) table
+(at V=50k it would be 10 GB).
 """
 from __future__ import annotations
 
@@ -16,11 +20,14 @@ from ..core.spaces import Discrete
 from .base import EnvSpec, EnvInfo
 
 
+def _chain_row(tok, vocab: int, temp: float, seed: int):
+    """Log-probs (V,) of the chain's transitions out of ``tok``."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), tok)
+    return jax.nn.log_softmax(temp * jax.random.normal(key, (vocab,)))
+
+
 def make_token_lm(vocab: int = 256, episode_len: int = 64, temp: float = 1.0,
                   seed: int = 0) -> EnvSpec:
-    # fixed environment dynamics: random transition logits (V, V)
-    chain_logits = temp * jax.random.normal(jax.random.PRNGKey(seed), (vocab, vocab))
-    chain_logp = jax.nn.log_softmax(chain_logits, axis=-1)
 
     def _fresh(rng):
         tok = jax.random.randint(rng, (), 0, vocab)
@@ -32,7 +39,7 @@ def make_token_lm(vocab: int = 256, episode_len: int = 64, temp: float = 1.0,
 
     def step(state, action, rng):
         a = action.astype(jnp.int32)
-        reward = chain_logp[state["tok"], a].astype(jnp.float32)
+        reward = _chain_row(state["tok"], vocab, temp, seed)[a]
         t = state["t"] + 1
         timeout = t >= episode_len
         done = timeout
@@ -55,5 +62,5 @@ def make_token_lm(vocab: int = 256, episode_len: int = 64, temp: float = 1.0,
 def chain_log_probs(vocab: int = 256, temp: float = 1.0, seed: int = 0):
     """The env's true transition log-probs (V, V) — for computing the optimal
     expected reward (greedy upper bound) in tests and learning curves."""
-    logits = temp * jax.random.normal(jax.random.PRNGKey(seed), (vocab, vocab))
-    return jax.nn.log_softmax(logits, axis=-1)
+    return jax.vmap(lambda t: _chain_row(t, vocab, temp, seed))(
+        jnp.arange(vocab))

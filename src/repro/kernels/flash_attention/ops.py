@@ -27,6 +27,16 @@ from .flash_attention import flash_attention_pallas
 from .ref import attention_reference
 
 
+def _row_block(T: int, G: int, block_q: int) -> int:
+    """Rows per block of the (G*T, dh) head-group matrix: ``block_q`` when
+    T >= block_q (T is padded to a multiple), else whole heads — T times the
+    largest divisor of G that keeps the block within ``block_q`` rows."""
+    if T >= block_q:
+        return block_q
+    gq = max(d for d in range(1, G + 1) if G % d == 0 and d * T <= block_q)
+    return gq * T
+
+
 def _pad_to(x, axis, mult):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -48,9 +58,9 @@ def _fa_impl(q, k, v, opts):
     causal, window, softcap, q_offset, block_q, block_k, interpret = opts
     B, T, H, dh = q.shape
     S = k.shape[1]
-    bq = min(block_q, max(T, 1))
+    bq = _row_block(T, H // k.shape[2], block_q)
     bk = min(block_k, max(S, 1))
-    qp, T0 = _pad_to(q, 1, bq)
+    qp, T0 = _pad_to(q, 1, bq if T >= block_q else 1)
     kp, S0 = _pad_to(k, 1, bk)
     vp, _ = _pad_to(v, 1, bk)
     if not causal and S0 != kp.shape[1]:
@@ -110,10 +120,12 @@ def _fa_decode_impl(q, k, v, kv_len, opts):
     kp, _ = _pad_to(k, 1, bk)
     vp, _ = _pad_to(v, 1, bk)
     # padded slots sit at positions >= S >= max(kv_len): masked by kv_len
-    return flash_attention_pallas(q, kp, vp, causal=False, window=None,
-                                  softcap=softcap, kv_len=kv_len,
-                                  block_q=min(block_q, max(T, 1)), block_k=bk,
-                                  interpret=interpret)
+    bq = _row_block(T, H // k.shape[2], block_q)
+    qp, T0 = _pad_to(q, 1, bq if T >= block_q else 1)
+    out = flash_attention_pallas(qp, kp, vp, causal=False, window=None,
+                                 softcap=softcap, kv_len=kv_len,
+                                 block_q=bq, block_k=bk, interpret=interpret)
+    return out[:, :T0]
 
 
 def flash_attention_decode(q, k, v, kv_len, *,

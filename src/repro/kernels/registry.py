@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import os
 import threading
+import warnings
 from contextlib import contextmanager
 from functools import lru_cache
 from typing import Dict, Optional
@@ -109,6 +110,13 @@ def backend_for(op: str, site: Optional[str] = None) -> str:
             be = layer[op]
     if be == "auto":
         be = _auto(op)
+    if be == "interpret":
+        import jax
+
+        if jax.default_backend() != "cpu":
+            warnings.warn(f"kernel op {op!r} runs in Pallas interpret mode "
+                          f"on {jax.default_backend()!r}: a debugging path, "
+                          f"not the compiled kernel", stacklevel=2)
     if site is not None:
         from ..telemetry import trace
 

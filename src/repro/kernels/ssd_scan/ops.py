@@ -18,8 +18,8 @@ from .. import registry
 from .ssd_scan import ssd_scan_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "block_h", "interpret"))
-def _ssd_impl(x, dt, A, Bmat, Cmat, *, chunk, block_h, interpret):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _ssd_impl(x, dt, A, Bmat, Cmat, *, chunk, interpret):
     B, T, H, P = x.shape
     pad = (-T) % chunk
     if pad:
@@ -28,15 +28,14 @@ def _ssd_impl(x, dt, A, Bmat, Cmat, *, chunk, block_h, interpret):
         Bmat = jnp.pad(Bmat, ((0, 0), (0, pad), (0, 0), (0, 0)))
         Cmat = jnp.pad(Cmat, ((0, 0), (0, pad), (0, 0), (0, 0)))
     y, s = ssd_scan_pallas(x, dt, A, Bmat, Cmat, chunk=chunk,
-                           block_h=block_h, interpret=interpret)
+                           interpret=interpret)
     return y[:, :T], s
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd(x, dt, A, Bmat, Cmat, opts):
-    chunk, block_h, interpret = opts
-    return _ssd_impl(x, dt, A, Bmat, Cmat, chunk=chunk, block_h=block_h,
-                     interpret=interpret)
+    chunk, interpret = opts
+    return _ssd_impl(x, dt, A, Bmat, Cmat, chunk=chunk, interpret=interpret)
 
 
 def _ssd_fwd(x, dt, A, Bmat, Cmat, opts):
@@ -60,16 +59,9 @@ def _ssd_bwd(opts, res, g):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 64, block_h: int = 8,
+def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 64,
              interpret: Optional[bool] = None):
     """x:(B,T,H,P) dt:(B,T,H) A:(H,)<0  B/C:(B,T,G,N) -> (y, final_state).
-    Differentiable (custom_vjp; backward via the jnp chunked scan).
-    block_h is clamped to divide H // G (head blocks must not cross SSD
-    group boundaries)."""
-    H, G = x.shape[2], Bmat.shape[2]
-    hpg = H // G
-    bh = min(block_h, hpg)
-    while hpg % bh:
-        bh -= 1
+    Differentiable (custom_vjp; backward via the jnp chunked scan)."""
     interpret = registry.resolve_interpret("ssd", interpret)
-    return _ssd(x, dt, A, Bmat, Cmat, (chunk, bh, interpret))
+    return _ssd(x, dt, A, Bmat, Cmat, (chunk, interpret))

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: the production
-mesh is built from 512 forced host devices (the two lines above MUST run
-before any other import — jax locks the device count at first init).
+mesh is built from 512 forced host devices.  ``main`` sets ``XLA_FLAGS``
+(see :func:`force_host_devices`) before the first device query — jax locks
+the device count when its backend starts, not at import.
 
 Per cell:
 1. REAL module (scan-over-layers, remat, microbatched) is lowered AND
@@ -23,6 +21,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -41,6 +40,8 @@ from . import specs as specs_lib
 from .hlo_analysis import collective_bytes, roofline_terms
 
 F32 = jnp.float32
+# the production meshes (launch/mesh.py) are TPU v5e pods
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 # gradient-accumulation microbatches per arch for train_4k (memory knob)
 DEFAULT_MICRO = {
@@ -354,7 +355,8 @@ def run_cell(arch: str, cell, *, multi_pod: bool, n_micro=None,
         cost = cost_from_variants(cfg, aid, cell, mesh, n_micro)
         roof = roofline_terms({"flops": cost["flops"],
                                "bytes accessed": cost["bytes"]},
-                              {"total": cost["coll"]}, n_chips)
+                              {"total": cost["coll"]}, n_chips,
+                              device_kind=TARGET_DEVICE_KIND)
         tokens = cell.tokens if cell.kind != "decode" else cell.global_batch
         mult = 6 if cell.kind == "train" else 2
         model_flops = mult * cfg.n_active_params() * tokens
@@ -387,7 +389,15 @@ def run_cell(arch: str, cell, *, multi_pod: bool, n_micro=None,
     return result
 
 
+def force_host_devices(n: int = 512) -> None:
+    """Ask the CPU backend for ``n`` devices (the production mesh size).
+    Must run before jax starts its backend; the process stays on the CPU."""
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
+
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -107,16 +107,37 @@ def xla_cost(fn, *args, **kwargs) -> Dict[str, float]:
             "bytes accessed": float(c.get("bytes accessed", 0.0))}
 
 
+# Published per-chip peaks, keyed by jax ``Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"add it to hlo_analysis.PEAKS (known: "
+                       f"{sorted(PEAKS)})") from None
+
+
 def roofline_terms(cost: dict, coll: dict, n_chips: int, *,
-                   peak_flops=197e12, hbm_bw=819e9, link_bw=50e9) -> dict:
-    """Three roofline terms in seconds (per the assignment formulas)."""
+                   device_kind: str) -> dict:
+    """Three roofline terms in seconds on ``device_kind``'s peaks."""
+    peaks = device_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     cbytes = float(coll.get("total", 0.0))
     # cost_analysis of the SPMD-partitioned module is already per-device.
-    t_compute = flops / peak_flops
-    t_memory = byts / hbm_bw
-    t_collective = cbytes / link_bw
+    t_compute = flops / peaks["flops_bf16"]
+    t_memory = byts / peaks["hbm_bw"]
+    t_collective = cbytes / peaks["ici_link_bw"]
     dom = max((("compute", t_compute), ("memory", t_memory),
                ("collective", t_collective)), key=lambda kv: kv[1])[0]
     return {

@@ -2,11 +2,13 @@
 stack/queue them over fixed local resources.
 
 The paper's example: an 8-GPU/40-CPU box running 30 variants 2-GPUs-each,
-4 at a time.  Here resources are MESH SLICES (or CPU slots in this
-container): the launcher runs up to ``capacity`` experiments concurrently,
-starting the next as slots free, recording results in a per-variant
-directory tree that mirrors the variant spec (paper: "results are recorded
-into a file structure which matches that of the variants generated").
+4 at a time.  On a TPU host a chip belongs to one process at a time, and a
+JAX process claims every chip of its host, so accelerator jobs run one at a
+time; CPU-only jobs (``JAX_PLATFORMS=cpu``) may run ``capacity`` at once.
+The launcher starts the next job as a slot frees, recording results in a
+per-variant directory tree that mirrors the variant spec (paper: "results
+are recorded into a file structure which matches that of the variants
+generated").
 
 Multi-pod: ``emit_pod_script`` writes the per-pod launch script that sets
 jax.distributed coordinator/process_id — the real-cluster path (cannot be
@@ -39,14 +41,22 @@ def variant_name(variant: Dict, keys: Sequence[str]) -> str:
     return "_".join(f"{k}-{variant[k]}" for k in keys)
 
 
-def launch_queue(commands: List[List[str]], *, capacity: int = 2,
+def launch_queue(commands: List[List[str]], *, capacity: int = 1,
                  log_dir: str = "runs", env_extra: Dict = None,
                  poll_s: float = 0.5) -> List[int]:
     """Run commands with at most ``capacity`` concurrent; returns exit codes.
 
     Each command i logs to {log_dir}/job_{i:03d}.log.  Slots are freed as
     jobs finish and the next queued job starts in its place (paper §6.6).
+    ``capacity > 1`` requires CPU-only jobs: two JAX processes must never
+    contend for one chip.
     """
+    base_env = dict(os.environ)
+    base_env.update(env_extra or {})
+    if capacity > 1 and base_env.get("JAX_PLATFORMS") != "cpu":
+        raise ValueError(
+            f"capacity={capacity}: concurrent jobs would share the host's "
+            f"chips; run one job at a time or set JAX_PLATFORMS=cpu")
     os.makedirs(log_dir, exist_ok=True)
     running: Dict[int, subprocess.Popen] = {}
     codes = [None] * len(commands)
@@ -55,9 +65,7 @@ def launch_queue(commands: List[List[str]], *, capacity: int = 2,
     while nxt < len(commands) or running:
         while nxt < len(commands) and len(running) < capacity:
             log = open(os.path.join(log_dir, f"job_{nxt:03d}.log"), "w")
-            env = dict(os.environ)
-            env.update(env_extra or {})
-            env["JOB_INDEX"] = str(nxt)
+            env = dict(base_env, JOB_INDEX=str(nxt))
             p = subprocess.Popen(commands[nxt], stdout=log, stderr=log, env=env)
             running[nxt] = p
             files[nxt] = log
@@ -73,7 +81,7 @@ def launch_queue(commands: List[List[str]], *, capacity: int = 2,
 
 
 def run_variants(script: str, variants: List[Dict], vary_keys: Sequence[str],
-                 *, capacity: int = 2, out_root: str = "runs",
+                 *, capacity: int = 1, out_root: str = "runs",
                  python: str = sys.executable) -> List[int]:
     """Launch {python} -m {script} --key value ... per variant, queued."""
     cmds, names = [], []

@@ -12,19 +12,30 @@ Mesh shapes (TPU v5e pod = 16x16 = 256 chips):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from ..models import sharding as shd
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the code relies on GSPMD
+    propagation and ``with_sharding_constraint`` (models/sharding.py), and
+    on shard_map bodies that reshape and gather freely — both of which the
+    ``Explicit`` axes ``jax.make_mesh`` now defaults to reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU tests (requires >=4 forced host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_data_mesh(n_data: int = 0, axis: str = "data"):
@@ -34,7 +45,7 @@ def make_data_mesh(n_data: int = 0, axis: str = "data"):
     device.  RL models are small, so there is no 'model' axis — scaling is
     pure data parallelism, unlike the LM meshes above."""
     n = n_data or jax.local_device_count()
-    return jax.make_mesh((n,), (axis,))
+    return auto_mesh((n,), (axis,))
 
 
 def make_2d_mesh(n_data: int = 0, n_model: int = 1,
@@ -56,7 +67,7 @@ def make_2d_mesh(n_data: int = 0, n_model: int = 1,
             f"mesh {n_data}x{n_model} needs {n_data * n_model} devices, "
             f"host has {avail} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N for CPU tests)")
-    return jax.make_mesh((n_data, n_model), tuple(axes))
+    return auto_mesh((n_data, n_model), axes)
 
 
 def parse_mesh_arg(spec: str):
@@ -125,8 +136,9 @@ def install_2d(mesh):
     """Register a (data x model) mesh for the shard_map'd train path.
 
     Unlike :func:`install`, the data axes are NOT registered as dp axes:
-    inside ``shard_map(..., auto={'model'})`` the batch dims are shard-local
-    (manual over 'data'), and a sharding constraint naming a manual axis is
+    inside ``jax.shard_map(..., axis_names={'data'})`` the batch dims are
+    shard-local (manual over 'data'), and a sharding constraint naming a
+    manual axis is
     an error — only the auto 'model' axis may appear in constraints.  Batch
     specs therefore resolve to unsharded dims while param/activation rules
     keep their model-axis sharding.
@@ -137,8 +149,3 @@ def install_2d(mesh):
     shd.set_global_mesh(mesh, dp_axes=(), tp_axis="model")
     return mesh
 
-
-# Hardware constants (TPU v5e) for the roofline (EXPERIMENTS.md §Roofline)
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link (~per chip, 1 link used)

@@ -37,6 +37,7 @@ from ..models import backbones as bb
 from ..serving import ContinuousBatchEngine, DEFAULT_BUCKETS, poisson_trace
 from ..telemetry import trace
 from ..telemetry.metrics import MetricsRegistry
+from ..utils.compile_cache import enable_compile_cache
 from ..kernels import registry as kernel_registry
 
 F32 = jnp.float32
@@ -238,20 +239,17 @@ def main(argv=None):
                     help="capture a jax.profiler trace into DIR (default "
                          "<log-dir>/profile)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = trace.configure(os.path.join(args.log_dir, "trace.jsonl")
                              if args.log_dir else None)
     registry = MetricsRegistry(args.log_dir, sinks=("console", "jsonl"),
                                jsonl_filename="serve.jsonl")
-    profile_dir = profile_started = None
+    profile_dir = None
     if args.profile is not None:
         profile_dir = args.profile or os.path.join(args.log_dir or ".",
                                                    "profile")
-        try:
-            jax.profiler.start_trace(profile_dir)
-            profile_started = True
-        except Exception as e:  # echo the dir only when tracing started
-            print(f"profiler trace did not start: {e}")
+        jax.profiler.start_trace(profile_dir)
 
     if args.kernels:
         kernel_registry.set_env(args.kernels)
@@ -265,7 +263,7 @@ def main(argv=None):
     else:
         out = _run_fixed(args, cfg, params, tracer, registry)
 
-    if profile_started:
+    if profile_dir is not None:
         jax.profiler.stop_trace()
         print(f"profiler trace written to {profile_dir}")
     registry.close()

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import os
 import time
 
@@ -37,6 +38,7 @@ from ..algos.pg.ppo import make_lm_ppo_train_step
 from ..telemetry import trace
 from ..train.optim import adam
 from ..train.checkpoint import save_checkpoint, restore_checkpoint, latest_step
+from ..utils.compile_cache import enable_compile_cache
 from ..utils.logger import Logger
 from ..kernels import registry as kernel_registry
 
@@ -90,7 +92,6 @@ def run_mesh(args, cfg, env, logger, tracer, rng, mesh_shape, shutdown):
     cross_replica collective — which is exactly the hook that lets
     --compress route it through the int8 error-feedback compressor.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..models import sharding as shd
@@ -113,16 +114,23 @@ def run_mesh(args, cfg, env, logger, tracer, rng, mesh_shape, shutdown):
           f"local batch {local_batch}, compress={args.compress or 'off'}")
 
     k_init, rng = jax.random.split(rng)
-    params = bb.init_lm(k_init, cfg)
-    pspecs = shd.param_pspecs(params, cfg)
-    params = jax.device_put(params, shd.make_shardings(pspecs, mesh))
+    init = functools.partial(bb.init_lm, cfg=cfg)
+    pspecs = shd.param_pspecs(jax.eval_shape(init, k_init), cfg)
+    # initialized in place, sharded: no device holds the whole model first
+    params = jax.jit(init, out_shardings=shd.make_shardings(pspecs, mesh))(
+        k_init)
     if args.compress:
         wb = wire_bytes(params)
         print(f"int8 all-reduce payload: {wb['int8_bytes']:,} B/step "
               f"(fp32 {wb['fp32_bytes']:,} B, {wb['ratio']:.2f}x reduction)")
 
-    opt = cross_replica(adam(args.lr, grad_clip=1.0), "data",
-                        compress=args.compress, ef_shards=n_data)
+    # over one data shard every 'data' collective is the identity, and
+    # XLA's partial-manual partitioner refuses an all-reduce over a size-1
+    # manual axis (RET_CHECK IsManualSubgroup): leave them out
+    opt = adam(args.lr, grad_clip=1.0)
+    if n_data > 1 or args.compress:
+        opt = cross_replica(opt, "data", compress=args.compress,
+                            ef_shards=n_data)
     opt_state = opt.init(params)
     ts_spec = cross_replica_specs("data") if args.compress else P()
 
@@ -156,19 +164,22 @@ def run_mesh(args, cfg, env, logger, tracer, rng, mesh_shape, shutdown):
         me = sid[0]
         metrics = {}
         for i in range(ks.shape[0]):
-            traj, v_last = rollout(params, jax.random.fold_in(ks[i], me))
+            # one data shard rolls out on the single-device path's keys
+            k = ks[i] if n_data == 1 else jax.random.fold_in(ks[i], me)
+            traj, v_last = rollout(params, k)
             batch = build_batch(traj, v_last)
             params, opt_state, metrics = train_step(params, opt_state, batch)
             metrics = dict(metrics, avg_reward=jnp.mean(traj["reward"]))
-        metrics = {name: jax.lax.pmean(v, "data")
-                   for name, v in metrics.items()}
+        if n_data > 1:
+            metrics = {name: jax.lax.pmean(v, "data")
+                       for name, v in metrics.items()}
         return params, opt_state, metrics
 
-    mesh_window = jax.jit(shard_map(
+    mesh_window = jax.jit(jax.shard_map(
         window, mesh=mesh,
         in_specs=(P(), ts_spec, P(), P("data")),
         out_specs=(P(), ts_spec, P()),
-        check_rep=False, auto=frozenset({"model"})))
+        check_vma=False, axis_names={"data"}))
     tracer.watch_jit("lm.mesh_window", mesh_window)
     shard_ids = jnp.arange(n_data, dtype=jnp.uint32)
 
@@ -213,7 +224,7 @@ def run_mesh(args, cfg, env, logger, tracer, rng, mesh_shape, shutdown):
     return params
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -251,7 +262,20 @@ def main(argv=None):
                          "DIR (default <log-dir>/profile) — loadable in "
                          "perfetto / tensorboard; host phases appear as the "
                          "telemetry span annotations")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    return run(args, cfg)
+
+
+def run(args, cfg: ModelConfig):
+    """Train ``cfg`` as the LM policy under the parsed ``args`` (the CLI's
+    ``--arch/--smoke`` choose the config in :func:`main`; callers may pass
+    any config, e.g. one cut in depth to fit a chip)."""
+    enable_compile_cache()
 
     # host-side telemetry: spans + recompile events to trace.jsonl when a
     # log dir exists, in-memory ring otherwise
@@ -266,7 +290,6 @@ def main(argv=None):
     if args.kernels:
         kernel_registry.set_env(args.kernels)
     print(f"kernel backends: {kernel_registry.describe()}")
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     env = make_token_lm(vocab=cfg.vocab, episode_len=args.horizon)
     logger = Logger(args.log_dir)
     rng = jax.random.PRNGKey(args.seed)
@@ -278,13 +301,17 @@ def main(argv=None):
             jax.profiler.stop_trace()
             print(f"profiler trace written to {profile_dir}")
 
-    from .mesh import parse_mesh_arg
+    from .mesh import install, parse_mesh_arg
     mesh_shape = parse_mesh_arg(args.mesh)
     if args.compress and mesh_shape is None:
-        ap.error("--compress requires --mesh DATAxMODEL (e.g. --mesh 2x2)")
+        raise SystemExit("--compress requires --mesh DATAxMODEL "
+                         "(e.g. --mesh 2x2)")
     if mesh_shape is not None:
         return run_mesh(args, cfg, env, logger, tracer, rng, mesh_shape,
                         _shutdown)
+    # a mesh an earlier run in this process installed must not partition
+    # this single-device program (Mosaic kernels cannot be auto-partitioned)
+    install(None)
 
     k_init, rng = jax.random.split(rng)
     params = bb.init_lm(k_init, cfg)
@@ -321,7 +348,9 @@ def main(argv=None):
         # dispatch modes run the exact same per-step program.
         from ..runners.train_loop import split_keys
 
-        @jax.jit
+        # params and optimizer state are donated: the window's outputs
+        # replace them, so one copy of each is resident, not two
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
         def fused_window(params, opt_state, ks):
             def body(carry, k):
                 p, o = carry

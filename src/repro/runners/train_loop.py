@@ -45,7 +45,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core.batch_spec import make_algo_batch
 from ..replay.interface import ReplayLike
@@ -348,19 +347,19 @@ class TrainLoop:
                 ts, ss, _, infos, sents = self._sharded_window_impl(
                     ts, ss, None, keys)
                 return ts, ss, infos, sents
-            f = shard_map(window, mesh=self.mesh,
-                          in_specs=(ts_spec, ss_spec, P()),
-                          out_specs=(ts_spec, ss_spec, P(), P()),
-                          check_rep=False)
+            f = jax.shard_map(window, mesh=self.mesh,
+                              in_specs=(ts_spec, ss_spec, P()),
+                              out_specs=(ts_spec, ss_spec, P(), P()),
+                              check_vma=False)
         else:
             rs_spec = self.replay.shard_spec(self.axis)
 
             def window(ts, ss, rs, keys):
                 return self._sharded_window_impl(ts, ss, rs, keys)
-            f = shard_map(window, mesh=self.mesh,
-                          in_specs=(ts_spec, ss_spec, rs_spec, P()),
-                          out_specs=(ts_spec, ss_spec, rs_spec, P(), P()),
-                          check_rep=False)
+            f = jax.shard_map(window, mesh=self.mesh,
+                              in_specs=(ts_spec, ss_spec, rs_spec, P()),
+                              out_specs=(ts_spec, ss_spec, rs_spec, P(), P()),
+                              check_vma=False)
         self._sharded_window = jax.jit(f)
         self.tracer.watch_jit("train_loop.sharded_window",
                               self._sharded_window)
@@ -385,9 +384,9 @@ class TrainLoop:
                 rs = self.replay.merge_view(
                     self.replay.insert(self.replay.local_view(rs), batch))
                 return ss, rs
-            self._sharded_ci = jax.jit(shard_map(
+            self._sharded_ci = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(P(), ss_spec, rs_spec),
-                out_specs=(ss_spec, rs_spec), check_rep=False))
+                out_specs=(ss_spec, rs_spec), check_vma=False))
         return self._sharded_ci(params, sampler_state, replay_state)
 
     # -- host drivers --------------------------------------------------------

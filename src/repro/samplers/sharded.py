@@ -23,7 +23,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .serial import SerialSampler, SamplerState
 
@@ -117,10 +116,10 @@ class ShardedSampler:
         batch_spec = jax.tree_util.tree_map(
             lambda l: P(None, axis) if l.ndim >= 2 else P(None), out_shapes[1])
 
-        f = shard_map(self.local_collect, mesh=self.mesh,
-                      in_specs=(params_spec, state_spec),
-                      out_specs=(state_spec, batch_spec),
-                      check_rep=False)
+        f = jax.shard_map(self.local_collect, mesh=self.mesh,
+                          in_specs=(params_spec, state_spec),
+                          out_specs=(state_spec, batch_spec),
+                          check_vma=False)
         return f(params, state)
 
     def bootstrap_value(self, params, state: SamplerState):

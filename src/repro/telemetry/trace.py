@@ -76,22 +76,17 @@ class Tracer:
 
     # -- recompilation detector ----------------------------------------------
     def watch_jit(self, name: str, fn) -> None:
-        """Register a jitted entry point for trace-cache-miss counting.
-        Functions without a ``_cache_size`` probe (non-jitted callables,
-        future jax versions dropping the attribute) are skipped."""
-        if hasattr(fn, "_cache_size"):
-            self._watched[name] = fn
-            self._cache_sizes.setdefault(name, 0)
+        """Register a jitted entry point (anything ``jax.jit`` returns) for
+        trace-cache-miss counting."""
+        self._watched[name] = fn
+        self._cache_sizes.setdefault(name, 0)
 
     def poll_recompiles(self) -> int:
         """Emit one ``recompile`` event per entry point whose trace cache
         grew since the last poll; returns the number of new compilations."""
         new_total = 0
         for name, fn in self._watched.items():
-            try:
-                n = fn._cache_size()
-            except Exception:
-                continue
+            n = fn._cache_size()
             prev = self._cache_sizes.get(name, 0)
             if n > prev:
                 self.emit("recompile", name, cache_size=n, n_new=n - prev)

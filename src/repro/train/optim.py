@@ -84,8 +84,9 @@ def adam(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
     sched = lr if callable(lr) else constant(lr)
 
     def init(params):
+        # zeros_like: each moment takes its parameter's sharding
         zeros = lambda: jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, F32), params)
+            lambda p: jnp.zeros_like(p, F32), params)
         return OptState(step=jnp.zeros((), jnp.int32), mu=zeros(), nu=zeros())
 
     def update(grads, state: OptState, params):
@@ -125,7 +126,7 @@ def sgd(lr, momentum: float = 0.0, grad_clip: Optional[float] = None) -> Optimiz
     sched = lr if callable(lr) else constant(lr)
 
     def init(params):
-        mu = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, F32), params)
+        mu = jax.tree_util.tree_map(lambda p: jnp.zeros_like(p, F32), params)
         return OptState(step=jnp.zeros((), jnp.int32), mu=mu, nu=None)
 
     def update(grads, state: OptState, params):

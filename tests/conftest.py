@@ -10,10 +10,12 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_with_devices(code: str, n_devices: int = 8, timeout: int = 300):
+def run_with_devices(code: str, n_devices: int = 8, timeout: int = 300,
+                     env_extra: dict = None):
     """Run python code in a subprocess with forced host devices."""
-    env = dict(os.environ)
+    env = dict(os.environ, **(env_extra or {}))
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices; the chip stays free
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=timeout)

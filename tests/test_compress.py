@@ -86,10 +86,10 @@ def test_cross_pod_allreduce_matches_pmean_within_bound():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.train.compress import EFState, cross_pod_allreduce
 
-mesh = jax.make_mesh((4,), ("pod",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4,), ("pod",))
 gs = jax.random.normal(jax.random.PRNGKey(0), (4, 32, 8), jnp.float32)
 
 def step(g, r):
@@ -98,9 +98,9 @@ def step(g, r):
     ref = jax.lax.pmean(g[0], "pod")
     return out["w"][None], ef.residual["w"][None], ref[None]
 
-f = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                      out_specs=(P("pod"), P("pod"), P("pod")),
-                      check_rep=False))
+f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                          out_specs=(P("pod"), P("pod"), P("pod")),
+                          check_vma=False))
 r = jnp.zeros_like(gs)
 out1, r, ref = f(gs, r)
 bound = float(np.mean(np.abs(np.asarray(gs)).max(axis=(1, 2)) / 127.0))

@@ -49,25 +49,25 @@ def test_flash_attention_vs_ref(case, dtype, rng):
 
 
 SSD_CASES = [
-    # B, T, H, P, G, N, chunk, block_h
-    (2, 128, 8, 16, 1, 32, 32, 4),
-    (1, 64, 4, 64, 1, 128, 64, 4),
-    (2, 96, 8, 32, 2, 16, 32, 4),
-    (1, 256, 16, 64, 4, 64, 64, 4),
-    (1, 32, 2, 8, 1, 8, 16, 2),
+    # B, T, H, P, G, N, chunk
+    (2, 128, 8, 16, 1, 32, 32),
+    (1, 64, 4, 64, 1, 128, 64),
+    (2, 96, 8, 32, 2, 16, 32),
+    (1, 256, 16, 64, 4, 64, 64),
+    (1, 32, 2, 8, 1, 8, 16),
 ]
 
 
 @pytest.mark.parametrize("case", SSD_CASES)
 def test_ssd_scan_vs_ref(case, rng):
-    B, T, H, P, G, N, chunk, bh = case
+    B, T, H, P, G, N, chunk = case
     ks = jax.random.split(rng, 5)
     x = jax.random.normal(ks[0], (B, T, H, P), jnp.float32) * 0.5
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, H)))
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
     Bm = jax.random.normal(ks[3], (B, T, G, N)) * 0.3
     Cm = jax.random.normal(ks[4], (B, T, G, N)) * 0.3
-    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, block_h=bh)
+    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
     yr, sr = ssd_reference(x, dt, A, Bm, Cm, chunk=chunk)
     scale = float(jnp.max(jnp.abs(yr))) + 1e-9
     np.testing.assert_allclose(np.asarray(y) / scale, np.asarray(yr) / scale,
@@ -85,7 +85,7 @@ def test_ssd_kernel_matches_backbone_math(rng):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.2)
     Bm = jax.random.normal(ks[3], (B, T, G, N)) * 0.3
     Cm = jax.random.normal(ks[4], (B, T, G, N)) * 0.3
-    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=16, block_h=2)
+    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=16)
     yr, sr = ssd_reference(x, dt, A, Bm, Cm, chunk=16)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-4)
 
@@ -265,8 +265,10 @@ def test_ssd_layer_backend_parity(rng):
 
 def test_device_replay_backend_parity(rng):
     """DeviceReplay insert / prioritized sample / update_priorities produce
-    identical trees, indices and weights under ref vs interpret (descent vs
-    blocked kernel share exact smallest-cumsum-above-u semantics)."""
+    identical indices and weights under ref vs interpret (descent vs blocked
+    kernel share exact smallest-cumsum-above-u semantics).  The trees agree
+    to a few ulp: XLA may merge the blocked rebuild's level sums into wider
+    reductions, so an internal node can be summed in another order."""
     from repro.replay import device as dreplay
 
     example = {"obs": jnp.zeros((4,)), "act": jnp.zeros((), jnp.int32)}
@@ -283,7 +285,9 @@ def test_device_replay_backend_parity(rng):
             st = dreplay.update_priorities(st, idx, jnp.linspace(0.1, 2.0, 32))
         outs[spec] = (st.tree, idx, w)
     assert bool(jnp.all(outs["ref"][1] == outs["interpret"][1]))
-    assert _tree_max_diff(outs["ref"][0], outs["interpret"][0]) == 0.0
+    np.testing.assert_allclose(np.asarray(outs["interpret"][0]),
+                               np.asarray(outs["ref"][0]),
+                               rtol=4 * np.finfo(np.float32).eps, atol=0)
     assert _tree_max_diff(outs["ref"][2], outs["interpret"][2]) == 0.0
 
 

@@ -64,6 +64,25 @@ print("shapes ok")
 """, n_devices=4)
 
 
+def test_lm_train_model_only_mesh_then_single_device():
+    """launch/train.py on --mesh 1x4 (one data shard: no data collectives
+    to trip the partial-manual partitioner), then --mesh 1x1 in the same
+    process: the single-device run must not inherit the earlier mesh."""
+    run_with_devices("""
+import jax, numpy as np
+from repro.launch import train
+from repro.models import sharding as shd
+for spec in ("1x4", "1x1"):
+    params = train.main(["--arch", "mamba2-1.3b", "--steps", "2", "--batch",
+                         "4", "--horizon", "8", "--fuse-window", "2",
+                         "--mesh", spec])
+    assert all(np.isfinite(np.asarray(x)).all()
+               for x in jax.tree_util.tree_leaves(params)), spec
+assert shd.get_global_mesh() is None
+print("ok")
+""", n_devices=4)
+
+
 def test_split_actor_learner_excludes_mesh_devices():
     """Regression: the async runner must not pin its actor or learner onto a
     device the data mesh owns — a shared device silently serializes the
@@ -97,7 +116,6 @@ def test_mesh2d_parity_uncompressed():
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs import get_smoke_config
 from repro.models import backbones as bb
 from repro.models import sharding as shd
@@ -141,10 +159,10 @@ def step(p, o, b):
     p, o, m = step_fn(p, o, b)
     return p, o, {k2: jax.lax.pmean(v, "data") for k2, v in m.items()}
 
-step_sh = jax.jit(shard_map(step, mesh=mesh,
-                            in_specs=(P(), P(), P("data")),
-                            out_specs=(P(), P(), P()), check_rep=False,
-                            auto=frozenset({"model"})))
+step_sh = jax.jit(jax.shard_map(step, mesh=mesh,
+                                in_specs=(P(), P(), P("data")),
+                                out_specs=(P(), P(), P()), check_vma=False,
+                                axis_names={"data"}))
 o_sh = opt_sh.init(p_sh)
 for _ in range(3):
     p_sh, o_sh, m_sh = step_sh(p_sh, o_sh, batch)
@@ -175,7 +193,6 @@ def test_mesh2d_ef_cumulative_convergence():
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs import get_smoke_config
 from repro.models import backbones as bb
 from repro.models import sharding as shd
@@ -218,9 +235,10 @@ def step(p, s, b):
     return p, s, {k2: jax.lax.pmean(v, "data") for k2, v in m.items()}
 
 spec = (cross_replica_specs("data"), P())
-step_sh = jax.jit(shard_map(step, mesh=mesh, in_specs=(P(), spec, P("data")),
-                            out_specs=(P(), spec, P()), check_rep=False,
-                            auto=frozenset({"model"})))
+step_sh = jax.jit(jax.shard_map(step, mesh=mesh,
+                                in_specs=(P(), spec, P("data")),
+                                out_specs=(P(), spec, P()), check_vma=False,
+                                axis_names={"data"}))
 
 B, T = 8, 16
 state = instr_init(params)

@@ -88,12 +88,13 @@ import jax, numpy as np, jax.numpy as jnp, tempfile, os
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train.checkpoint import save_checkpoint, restore_checkpoint
 d = tempfile.mkdtemp()
-mesh4 = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import auto_mesh
+mesh4 = auto_mesh((4,), ("data",))
 x = jax.device_put(jnp.arange(32.0).reshape(8, 4),
                    NamedSharding(mesh4, P("data")))
 save_checkpoint(d, 1, {"x": x}, mesh_shape=(4,))
 for n in (2, 8):
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = auto_mesh((n,), ("data",))
     sh = {"x": NamedSharding(mesh, P("data"))}
     out, _ = restore_checkpoint(d, {"x": jnp.zeros((8, 4))}, shardings=sh)
     assert len(out["x"].sharding.device_set) == n
@@ -111,7 +112,8 @@ from repro.envs import make_env
 from repro.agents import make_categorical_pg_agent
 from repro.models.rl_models import make_pg_mlp
 from repro.samplers.sharded import ShardedSampler
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4, 2), ("data", "model"))
 env = make_env("cartpole")
 model = make_pg_mlp(4, 2)
 agent = make_categorical_pg_agent(model)
@@ -132,10 +134,10 @@ def test_ef_compression_cross_pod():
     quantization tolerance, residual carries the error."""
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.train.compress import cross_pod_allreduce, EFState
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 4), ("pod", "data"))
 g = jnp.arange(8.0).reshape(2, 4) / 7.0
 
 def f(g_shard, res):
@@ -143,8 +145,8 @@ def f(g_shard, res):
                                    EFState(residual={"w": res}), axis="pod")
     return out["w"], ef2.residual["w"]
 
-fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-               out_specs=(P("pod"), P("pod")), check_rep=False)
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                   out_specs=(P("pod"), P("pod")), check_vma=False)
 out, res = fn(g, jnp.zeros((2, 4)))
 expect = np.mean(np.asarray(g), axis=0)  # mean across the 2 pods
 got = np.asarray(out)
@@ -165,7 +167,7 @@ from repro.configs import get_smoke_config
 from repro.models.config import ShapeCell
 from repro.launch import mesh as mesh_lib
 from repro.launch.dryrun import build_train, build_decode, measure, _variant_cfg
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = mesh_lib.auto_mesh((2, 2), ("data", "model"))
 mesh_lib.install(mesh)
 cfg = get_smoke_config("glm4-9b")
 cell = ShapeCell("t", 32, 8, "train")
@@ -177,3 +179,32 @@ m2 = measure(*build_decode(_variant_cfg(cfg, 2), "glm4_9b", cell2, mesh))
 assert m2["flops"] > 0, m2
 print("dryrun-small ok")
 """, n_devices=4)
+
+
+def test_launch_queue_one_job_per_chip(tmp_path, monkeypatch):
+    """Accelerator jobs run one at a time (a chip belongs to one process);
+    concurrent slots are only for CPU-only jobs."""
+    import sys
+    from repro.launch.launcher import launch_queue
+
+    cmds = [[sys.executable, "-c", f"raise SystemExit({i})"] for i in range(3)]
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(ValueError, match="chips"):
+        launch_queue(cmds, capacity=2, log_dir=str(tmp_path))
+    assert launch_queue(cmds, log_dir=str(tmp_path)) == [0, 1, 2]
+    assert launch_queue(cmds, capacity=2, log_dir=str(tmp_path),
+                        env_extra={"JAX_PLATFORMS": "cpu"}) == [0, 1, 2]
+
+
+def test_device_peaks_known_kind_only():
+    """Roofline peaks come from one table keyed by device_kind; a device
+    missing from it is an error, never a silent v5e default."""
+    from repro.launch.hlo_analysis import device_peaks, roofline_terms
+
+    assert device_peaks("TPU v5 lite")["flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="cpu"):
+        device_peaks("cpu")
+    r = roofline_terms({"flops": 197e12, "bytes accessed": 819e9},
+                       {"total": 0.0}, 1, device_kind="TPU v5 lite")
+    assert r["t_compute_s"] == pytest.approx(1.0)
+    assert r["t_memory_s"] == pytest.approx(1.0)

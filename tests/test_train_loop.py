@@ -163,7 +163,8 @@ from repro.envs import make_env
 from repro.agents import make_categorical_pg_agent
 from repro.models.rl_models import make_pg_mlp
 from repro.samplers.sharded import ShardedSampler
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4,), ("data",))
 env = make_env("cartpole")
 model = make_pg_mlp(4, 2)
 agent = make_categorical_pg_agent(model)
