@@ -195,6 +195,7 @@ def make_lm_ppo_train_step(cfg, optimizer: Optimizer, *,
         total = pi_loss + value_coeff * v_loss - entropy_coeff * ent + aux_coeff * aux
         return total, {"pi_loss": pi_loss, "v_loss": v_loss, "entropy": ent}
 
+    @jax.named_scope("ppo_update")   # the update's device ops carry it
     def train_step(params, opt_state, batch):
         B = batch["tokens"].shape[0]
         assert B % n_microbatches == 0

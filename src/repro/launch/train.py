@@ -51,6 +51,7 @@ def make_lm_rollout(cfg: ModelConfig, env, batch: int, horizon: int,
     env step, cache carried through a lax.scan."""
     V = env.action_space.n
 
+    @jax.named_scope("lm_rollout")   # the rollout's device ops carry it
     def rollout(params, rng):
         k_env, k_roll = jax.random.split(rng)
         env_state, obs = jax.vmap(env.reset)(jax.random.split(k_env, batch))

@@ -32,8 +32,9 @@ import numpy as np
 
 from ..models import backbones as bb
 from ..models.config import ModelConfig
+from ..telemetry.trace import get_tracer
 from .scheduler import Scheduler
-from .slots import DEFAULT_BUCKETS, SlotCache
+from .slots import DEFAULT_BUCKETS, SlotCache, bucket_for
 from .workload import Request, summarize_requests
 
 F32 = jnp.float32
@@ -122,16 +123,32 @@ class ContinuousBatchEngine:
 
         ``realtime=False`` treats all arrivals as immediate (offline batch)
         — useful for deterministic tests.
+
+        Spans, counters and samples go to ``tracer`` (the process-global
+        tracer when None); recompiles are polled only on a given
+        ``tracer``.  Every host phase of the loop is a span, so the device's
+        idle gaps in a profile fall under the phase that left it idle:
+        ``serving.arrivals``, ``serving.admit`` (with the slot cache's
+        ``serving.prefill``, ``serving.tail_advance``, ``serving.slot_write``
+        and the closing ``serving.admit_wait``), ``serving.decode``,
+        ``serving.bookkeeping`` (which also splits the next block's key) and
+        ``serving.idle``.  This run alone adds to the counters
+        ``serving.admitted`` and ``serving.tail_steps``, and records each request's queue wait
+        (admission start, or the run's end for a rejected request, minus
+        its arrival) as the sample ``serving.queue_wait_s`` under its rid.
         """
         assert mode in ("continuous", "static")
+        tr = tracer if tracer is not None else get_tracer()
         self.slots.reset_all()
         sched = Scheduler(self.n_slots, self.max_queue)
         pending = sorted(trace, key=lambda r: r.arrival_s)
+        rejected: List[Request] = []
         slot_req: List[Optional[Request]] = [None] * self.n_slots
         active = np.zeros(self.n_slots, bool)
         remaining = np.zeros(self.n_slots, np.int32)
-        rng = jax.random.PRNGKey(self.seed)
-        decode_s = prefill_s = 0.0
+        rng, k = jax.random.split(jax.random.PRNGKey(self.seed))
+        decode_s0 = tr.span_seconds("serving.decode")
+        admit_s0 = tr.span_seconds("serving.admit")
         valid_tokens = n_blocks = recompiles = 0
         prefill_tok0 = self.slots.prefill_tokens
         i_next = 0
@@ -145,23 +162,23 @@ class ContinuousBatchEngine:
 
         while i_next < len(pending) or sched.n_waiting or active.any():
             # arrivals up to the current clock
-            while i_next < len(pending) and (
-                    not realtime or pending[i_next].arrival_s <= now()):
-                if not realtime:  # offline batch: whole trace present at t=0
-                    pending[i_next].arrival_s = 0.0
-                sched.submit(pending[i_next])
-                i_next += 1
+            with tr.span("serving.arrivals") as attrs:
+                n0 = i_next
+                while i_next < len(pending) and (
+                        not realtime or pending[i_next].arrival_s <= now()):
+                    if not realtime:  # offline batch: whole trace at t=0
+                        pending[i_next].arrival_s = 0.0
+                    if not sched.submit(pending[i_next]):
+                        rejected.append(pending[i_next])
+                    i_next += 1
+                attrs["n"] = i_next - n0
             # admission: continuous fills any free slot; static only admits
             # into an empty batch (the lockstep fixed-batch baseline)
             if mode == "continuous" or not active.any():
                 while (pair := sched.admit()) is not None:
                     req, slot = pair
-                    tp = time.perf_counter()
-                    self.slots.write_prefill_at(self.params, slot, req.prompt)
-                    jax.block_until_ready(self.slots.logits)
-                    prefill_s += time.perf_counter() - tp
+                    self._admit(tr, req, slot, now())
                     req.t_admitted = now()
-                    req.tokens = []
                     slot_req[slot] = req
                     active[slot] = True
                     remaining[slot] = req.max_tokens
@@ -169,44 +186,57 @@ class ContinuousBatchEngine:
                 if i_next < len(pending):  # idle until the next arrival
                     gap = pending[i_next].arrival_s - now()
                     if realtime and gap > 0:
-                        time.sleep(min(gap, 0.02))
+                        with tr.span("serving.idle"):
+                            time.sleep(min(gap, 0.02))
                 continue
 
-            rng, k = jax.random.split(rng)
-            td = time.perf_counter()
-            logits, cache, act_d, rem_d, toks, emitted = self._decode_block(
-                self.params, self.slots.logits, self.slots.cache,
-                jnp.asarray(active), jnp.asarray(remaining), k)
-            toks = np.asarray(toks)          # (block, n_slots)
-            emitted = np.asarray(emitted)    # (block, n_slots) bool
-            decode_s += time.perf_counter() - td
+            with tr.span("serving.decode", block_index=n_blocks,
+                         n_active=int(active.sum())):
+                logits, cache, act_d, rem_d, toks, emitted = \
+                    self._decode_block(self.params, self.slots.logits,
+                                       self.slots.cache, jnp.asarray(active),
+                                       jnp.asarray(remaining), k)
+                toks = np.asarray(toks)          # (block, n_slots)
+                emitted = np.asarray(emitted)    # (block, n_slots) bool
+                new_active = np.array(act_d)   # np.array: views are read-only
+                remaining = np.array(rem_d)
             n_blocks += 1
             self.slots.logits, self.slots.cache = logits, cache
-            new_active = np.array(act_d)   # np.array: device views are read-only
-            remaining = np.array(rem_d)
             t_block = now()
-            valid_tokens += int(emitted.sum())
-
-            for s in range(self.n_slots):
-                req = slot_req[s]
-                if req is None:
-                    continue
-                out = toks[emitted[:, s], s]
-                if out.size:
-                    req.tokens.extend(out.tolist())
-                    req.n_generated += int(out.size)
-                    if req.t_first_token is None:
-                        req.t_first_token = t_block
-                if active[s] and not new_active[s]:  # retired this block
-                    req.t_finished = t_block
-                    req.tokens = np.asarray(req.tokens, np.int32)
-                    slot_req[s] = None
-                    sched.release(s)
-            active = new_active
-            if tracer is not None:
-                recompiles += tracer.poll_recompiles()
+            with tr.span("serving.bookkeeping") as attrs:
+                valid_tokens += int(emitted.sum())
+                n_retired = 0
+                for s in range(self.n_slots):
+                    req = slot_req[s]
+                    if req is None:
+                        continue
+                    out = toks[emitted[:, s], s]
+                    if out.size:
+                        req.tokens.extend(out.tolist())
+                        req.n_generated += int(out.size)
+                        if req.t_first_token is None:
+                            req.t_first_token = t_block
+                    if active[s] and not new_active[s]:  # retired this block
+                        req.t_finished = t_block
+                        req.tokens = np.asarray(req.tokens, np.int32)
+                        tr.observe("serving.queue_wait_s",
+                                   req.t_admit_start - req.arrival_s,
+                                   key=req.rid)
+                        slot_req[s] = None
+                        sched.release(s)
+                        n_retired += 1
+                active = new_active
+                if tracer is not None:
+                    recompiles += tracer.poll_recompiles()
+                rng, k = jax.random.split(rng)   # the next block's key
+                attrs["n_retired"] = n_retired
 
         wall = now()
+        for req in rejected:
+            tr.observe("serving.queue_wait_s", wall - req.arrival_s,
+                       key=req.rid)
+        decode_s = tr.span_seconds("serving.decode") - decode_s0
+        prefill_s = tr.span_seconds("serving.admit") - admit_s0
         decode_slot_steps = n_blocks * self.block * self.n_slots
         summary = {
             "mode": mode,
@@ -223,3 +253,20 @@ class ContinuousBatchEngine:
             "recompile_events": recompiles,
         }
         return summary
+
+    def _admit(self, tr, req: Request, slot: int, t: float) -> None:
+        """Install ``req`` at ``slot``: its prefill, tail advance and slot
+        write, ended by waiting for the slot's logits."""
+        plen = req.prompt_len
+        bucket = bucket_for(plen, self.slots.buckets)
+        req.t_admit_start = t
+        req.tokens = []
+        tr.count("serving.admitted")
+        tr.count("serving.tail_steps", plen - bucket)
+        with tr.span("serving.admit", rid=req.rid, slot=slot,
+                     prompt_len=plen, bucket=bucket,
+                     tail_steps=plen - bucket):
+            self.slots.write_prefill_at(self.params, slot, req.prompt,
+                                        tracer=tr)
+            with tr.span("serving.admit_wait"):
+                jax.block_until_ready(self.slots.logits)

@@ -37,6 +37,7 @@ import numpy as np
 
 from ..models import backbones as bb
 from ..models.config import ModelConfig
+from ..telemetry.trace import get_tracer
 
 F32 = jnp.float32
 
@@ -135,20 +136,28 @@ class SlotCache:
                                    enc_len=self.cfg.enc_len)
         self.logits = jnp.zeros((self.n_slots, self.cfg.padded_vocab), F32)
 
-    def write_prefill_at(self, params, slot: int, prompt: np.ndarray) -> None:
-        """Prefill ``prompt`` single-sequence and install it at ``slot``."""
+    def write_prefill_at(self, params, slot: int, prompt: np.ndarray, *,
+                         tracer=None) -> None:
+        """Prefill ``prompt`` single-sequence and install it at ``slot``;
+        its three phases are the spans ``serving.prefill``,
+        ``serving.tail_advance`` and ``serving.slot_write`` on ``tracer``
+        (the process-global tracer when None)."""
+        tr = tracer if tracer is not None else get_tracer()
         plen = int(prompt.shape[0])
         if plen >= self.max_context:
             raise ValueError(f"prompt_len {plen} >= max_context "
                              f"{self.max_context}")
         b = bucket_for(plen, self.buckets)
-        tokens = jnp.asarray(prompt[None, :b], jnp.int32)
-        logits1, cache1 = self._prefill[b](params, tokens)
-        for i in range(b, plen):  # exact tail advance, shape-stable (B=1)
-            logits1, cache1 = self._advance(
-                params, cache1, jnp.asarray(prompt[i:i + 1], jnp.int32))
-        self.cache, self.logits = self._write(self.cache, self.logits,
-                                              cache1, logits1, slot)
+        with tr.span("serving.prefill", bucket=b):
+            tokens = jnp.asarray(prompt[None, :b], jnp.int32)
+            logits1, cache1 = self._prefill[b](params, tokens)
+        with tr.span("serving.tail_advance", steps=plen - b):
+            for i in range(b, plen):  # exact tail advance, shape-stable (B=1)
+                logits1, cache1 = self._advance(
+                    params, cache1, jnp.asarray(prompt[i:i + 1], jnp.int32))
+        with tr.span("serving.slot_write"):
+            self.cache, self.logits = self._write(self.cache, self.logits,
+                                                  cache1, logits1, slot)
         self.prefill_tokens += plen
 
     def reset_slot(self, slot: int) -> None:

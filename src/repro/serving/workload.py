@@ -29,6 +29,7 @@ class Request:
     arrival_s: float = 0.0
 
     # filled in by the engine
+    t_admit_start: Optional[float] = None
     t_admitted: Optional[float] = None
     t_first_token: Optional[float] = None
     t_finished: Optional[float] = None

@@ -231,3 +231,30 @@ def test_lm_ppo_microbatch_invariance(rng):
     # bf16 forward: summation order across micro splits costs ~1e-3 rel
     assert max(jax.tree_util.tree_leaves(diffs)) < 3e-3
     assert abs(float(metrics[0]["loss"]) - float(metrics[1]["loss"])) < 1e-5
+
+
+def test_lm_rollout_and_update_name_their_device_ops(rng):
+    """Every operation of the compiled LM rollout and PPO update carries
+    its scope (``lm_rollout``, ``ppo_update``) in its op_name, by which a
+    device trace can attribute time."""
+    import re
+    from repro.configs import get_smoke_config
+    from repro.envs.token_lm import make_token_lm
+    from repro.launch.train import make_lm_rollout
+    from repro.models import backbones as bb
+    from repro.train.optim import adam
+    cfg = get_smoke_config("mamba2-1.3b")
+    env = make_token_lm(vocab=cfg.vocab, episode_len=8)
+    params = jax.eval_shape(lambda k: bb.init_lm(k, cfg), rng)
+    roll = jax.jit(make_lm_rollout(cfg, env, 2, 8)).lower(params, rng)
+    opt = adam(1e-3)
+    batch = {k: jax.ShapeDtypeStruct((2, 8), jnp.int32 if k in (
+        "tokens", "actions") else jnp.float32)
+        for k in ("tokens", "actions", "logp_old", "advantage", "return_")}
+    step = jax.jit(make_lm_ppo_train_step(cfg, opt)).lower(
+        params, jax.eval_shape(opt.init, params), batch)
+    for lowered, scope in ((roll, "lm_rollout"), (step, "ppo_update")):
+        names = [n for n in re.findall(r'op_name="([^"]*)"',
+                                       lowered.compile().as_text())
+                 if n.startswith("jit(")]
+        assert names and all(f")/{scope}" in n for n in names), scope

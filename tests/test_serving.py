@@ -214,3 +214,138 @@ def test_engine_eos_retires_early():
     for r in reqs2:
         assert r.t_finished is not None
         assert r.n_generated <= 6
+
+
+def test_engine_samples_block_i_with_the_ith_split_of_its_seed():
+    """At temperature > 0 decode block i draws with the i-th key of the
+    chain ``rng, k = split(rng)`` from ``PRNGKey(seed)``, wherever in the
+    loop the split happens."""
+    cfg = get_smoke_config("mamba2-1.3b")
+    params = _params(cfg)
+    engine = ContinuousBatchEngine(cfg, params, n_slots=1, max_context=24,
+                                   buckets=(8,), decode_block=2,
+                                   temperature=1.0, seed=5)
+    engine.warmup()
+    prompt = np.arange(1, 9, dtype=np.int32)
+    req = Request(rid=0, prompt=prompt, max_tokens=6, arrival_s=0.0)
+    engine.run([req], realtime=False)
+
+    engine.slots.reset_all()
+    engine.slots.write_prefill_at(params, 0, prompt)
+    logits, cache = engine.slots.logits, engine.slots.cache
+    active, remaining = jnp.ones((1,), bool), jnp.full((1,), 6, jnp.int32)
+    rng, want = jax.random.PRNGKey(5), []
+    for _ in range(3):
+        rng, k = jax.random.split(rng)
+        logits, cache, active, remaining, toks, emitted = \
+            engine._decode_block(params, logits, cache, active, remaining, k)
+        want += np.asarray(toks)[np.asarray(emitted)].tolist()
+    assert req.tokens.tolist() == want and len(want) == 6
+
+
+# -- the engine's spans, counters and samples ---------------------------------
+
+SUMMARY_KEYS = {"mode", "n_requests", "n_rejected", "n_finished",
+                "p50_latency_s", "p99_latency_s", "mean_latency_s",
+                "ttft_p50_s", "ttft_p99_s", "generated_tokens",
+                "decode_tok_per_sec", "decode_step_ms", "prefill_tok_per_sec",
+                "slot_occupancy", "wall_s", "recompile_events"}
+
+
+@pytest.fixture(scope="module")
+def traced_run():
+    """One offline run of a 10-request trace through a 3-slot mamba2 engine
+    with a 4-request queue (so 6 are rejected), on a fresh global tracer
+    and a tracer of its own; warm-up goes to the global tracer only."""
+    from repro.telemetry import trace
+
+    cfg = get_smoke_config("mamba2-1.3b")
+    engine = ContinuousBatchEngine(cfg, _params(cfg), n_slots=3,
+                                   max_context=36, buckets=(8, 16),
+                                   decode_block=4, max_queue=4)
+    glob = trace.configure(None)
+    engine.warmup()
+    counted_in_warmup = dict(glob.counters)
+    tracer = trace.Tracer()
+    engine.watch(tracer)
+    reqs = poisson_trace(3, 10, 100.0, prompt_len_range=(8, 20),
+                         max_tokens_range=(4, 14), vocab=cfg.vocab)
+    summary = engine.run(reqs, tracer=tracer, realtime=False)
+    return engine, tracer, reqs, summary, counted_in_warmup
+
+
+def _spans(tracer, name):
+    return [e for e in tracer.events if e["kind"] == "span"
+            and e["name"] == name]
+
+
+def test_engine_admit_spans_name_each_request_and_its_phases(traced_run):
+    engine, tracer, reqs, _, _ = traced_run
+    admitted = [r for r in reqs if r.t_admitted is not None]
+    admits = _spans(tracer, "serving.admit")
+    assert sorted(e["rid"] for e in admits) == sorted(r.rid for r in admitted)
+    by_rid = {r.rid: r for r in admitted}
+    for e in admits:
+        r = by_rid[e["rid"]]
+        assert e["prompt_len"] == r.prompt_len
+        assert e["bucket"] == bucket_for(r.prompt_len, engine.slots.buckets)
+        assert e["tail_steps"] == r.prompt_len - e["bucket"]
+        assert e["start"] <= e["end"]
+    admit_ids = {e["id"] for e in admits}
+    for child in ("serving.prefill", "serving.tail_advance",
+                  "serving.slot_write", "serving.admit_wait"):
+        kids = _spans(tracer, child)
+        assert len(kids) == len(admits), child
+        assert {e["parent"] for e in kids} == admit_ids, child
+    by_id = {e["id"]: e for e in admits}
+    for e in _spans(tracer, "serving.tail_advance"):
+        assert e["steps"] == by_id[e["parent"]]["tail_steps"]
+    for name in ("serving.admit", "serving.decode", "serving.arrivals",
+                 "serving.bookkeeping"):
+        assert all(e["parent"] is None for e in _spans(tracer, name)), name
+
+
+def test_engine_counts_tail_steps_of_its_own_run_only(traced_run):
+    engine, tracer, reqs, _, counted_in_warmup = traced_run
+    admitted = [r for r in reqs if r.t_admitted is not None]
+    assert counted_in_warmup == {}
+    assert tracer.counters["serving.admitted"] == len(admitted) == 4
+    assert tracer.counters["serving.tail_steps"] == sum(
+        r.prompt_len - bucket_for(r.prompt_len, engine.slots.buckets)
+        for r in admitted) == 12
+    assert tracer.totals["serving.decode"].count == \
+        len(_spans(tracer, "serving.decode")) > 0
+    retired = sum(e["n_retired"] for e in _spans(tracer, "serving.bookkeeping"))
+    assert retired == len(admitted)
+
+
+def test_engine_queue_waits_are_within_each_ttft(traced_run):
+    _, tracer, reqs, summary, _ = traced_run
+    waits = tracer.samples["serving.queue_wait_s"]
+    assert sorted(waits) == [r.rid for r in reqs]   # rejected ones too
+    for r in reqs:
+        assert waits[r.rid] >= 0
+        if r.t_first_token is not None:
+            assert r.t_admit_start <= r.t_admitted
+            assert waits[r.rid] <= r.t_first_token - r.arrival_s
+        else:                       # rejected: its wait to the run's end
+            assert waits[r.rid] == pytest.approx(summary["wall_s"]
+                                                 - r.arrival_s)
+
+
+def test_engine_summary_keeps_its_keys_and_meaning(traced_run):
+    """The keys and the deterministic values are those of the engine before
+    it was traced; its timings are the decode and admit span totals."""
+    engine, tracer, reqs, summary, _ = traced_run
+    assert set(summary) == SUMMARY_KEYS
+    assert (summary["n_requests"], summary["n_rejected"],
+            summary["n_finished"], summary["generated_tokens"],
+            summary["slot_occupancy"], summary["recompile_events"]) == \
+        (10, 6, 4, 36, 0.75, 0)
+    assert summary["generated_tokens"] == sum(r.n_generated for r in reqs)
+    blocks = tracer.totals["serving.decode"].count
+    assert summary["decode_step_ms"] == pytest.approx(
+        1e3 * tracer.span_seconds("serving.decode") / (blocks * engine.block))
+    prompt_tokens = sum(r.prompt_len for r in reqs if r.t_admitted is not None)
+    assert summary["prefill_tok_per_sec"] == pytest.approx(
+        prompt_tokens / tracer.span_seconds("serving.admit"))

@@ -196,6 +196,85 @@ def test_tracer_jsonl_round_trip(tmp_path):
     assert [e["kind"] for e in t.events] == ["custom", "span"]
 
 
+def test_span_ids_parents_and_self_seconds():
+    t = trace.Tracer()
+    with t.span("outer", rid=7) as attrs:
+        with t.span("inner"):
+            pass
+        with t.span("inner"):
+            pass
+        attrs["n"] = 2                       # known only at the end
+    with t.span("outer"):
+        pass
+    ev = [e for e in t.events if e["kind"] == "span"]
+    assert [e["name"] for e in ev] == ["inner", "inner", "outer", "outer"]
+    inner1, inner2, outer1, outer2 = ev
+    assert len({e["id"] for e in ev}) == 4
+    assert inner1["parent"] == inner2["parent"] == outer1["id"]
+    assert outer1["parent"] is None and outer2["parent"] is None
+    assert outer1["rid"] == 7 and outer1["n"] == 2
+    assert outer1["start"] <= inner1["start"] <= inner1["end"] \
+        <= inner2["start"] <= inner2["end"] <= outer1["end"]
+    tot = t.totals["outer"]
+    assert tot.count == 2 and t.totals["inner"].count == 2
+    kids = t.totals["inner"].seconds
+    assert tot.self_seconds == pytest.approx(tot.seconds - kids)
+    assert t.span_seconds("outer") == tot.seconds
+    assert t.span_seconds("never") == 0.0
+
+
+def test_totals_counters_and_samples_outlive_the_ring():
+    """A reader never depends on the ring's size: totals, counters and
+    samples hold every span, count and sample however few events the ring
+    keeps; ``configure`` starts them afresh."""
+    t = trace.Tracer(ring_capacity=8)
+    for i in range(50):
+        with t.span("step", i=i):
+            t.count("items", 3)
+            t.observe("wait_s", i / 10, key=i)
+    t.observe("wait_s", 9.0, key=49)          # a key's sample is replaced
+    assert len(t.events) == 8
+    assert t.totals["step"].count == 50
+    assert t.counters["items"] == 150
+    assert len(t.samples["wait_s"]) == 50 and t.samples["wait_s"][49] == 9.0
+    g = trace.configure(None)
+    with g.span("x"):
+        g.count("c")
+    g = trace.configure(None)
+    assert not g.totals and not g.counters and not g.samples
+
+
+def test_span_annotation_joins_its_record_by_id(tmp_path):
+    """The profiler's host event of a span carries its id, parent and
+    attributes, so the trace and the in-memory record join by id."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    t = trace.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("join.outer", rid=5) as attrs:
+            with t.span("join.inner"):
+                pass
+            attrs["n"] = 3
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("join."):
+                    found[ev.name] = dict(ev.stats)
+    rec = {e["name"]: e for e in t.events}
+    assert found["join.outer"] == {"id": rec["join.outer"]["id"], "rid": 5,
+                                   "n": 3}
+    assert found["join.inner"] == {"id": rec["join.inner"]["id"],
+                                   "parent": rec["join.outer"]["id"]}
+
+
 def test_registry_jsonl_matches_csv(tmp_path):
     reg = MetricsRegistry(str(tmp_path), sinks=("csv", "jsonl"))
     reg.record(10, {"loss": 0.5, "sps": 1000.0})
