@@ -354,6 +354,37 @@ def value_out(params, hidden):
                       params["value_head"])[..., 0]
 
 
+# Leaves the forward reads only through ``.astype(cdtype(cfg))`` or
+# ``.astype(x.dtype)`` on a compute-dtype activation: the matmul weights,
+# the conv taps and the token table (a gather then a cast equals a cast then
+# a gather).  Norm scales, ``A_log``, ``dt_bias`` and ``value_head`` are read
+# in float32 and are not listed.
+_COMPUTE_DTYPE_LEAVES = frozenset({
+    "tok_embed", "lm_head",
+    "wq", "wk", "wv", "wo",                              # attention
+    "wi", "wg", "wd",                                    # SwiGLU MLP
+    "router", "experts_wi", "experts_wg", "experts_wd",  # MoE
+    "wz", "wx", "wB", "wC", "wdt", "conv_w", "out_proj",  # Mamba-2 SSD
+})
+
+
+def compute_weights(params, cfg: ModelConfig):
+    """``params`` with every leaf that the forward casts at use held in the
+    compute dtype already, so a program that serves many calls on frozen
+    weights does not cast them on each call.  Every product gets the operand
+    the cast at use gives it, so prefill, decode and logits are bitwise those
+    of the float32 tree wherever the compiler rounds at that cast (always on
+    the CPU; a TPU compile of a one-token program may leave a float32 weight
+    unrounded).  Leaves read in float32 keep their dtype; idempotent."""
+    dt = cdtype(cfg)
+
+    def cast(path, leaf):
+        name = getattr(path[-1], "key", None)
+        return leaf.astype(dt) if name in _COMPUTE_DTYPE_LEAVES else leaf
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 # ---------------------------------------------------------------------------
 # Caches
 # ---------------------------------------------------------------------------

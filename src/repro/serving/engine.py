@@ -24,6 +24,7 @@ differ *only* in slot swapping, which is exactly what
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import List, Optional, Sequence
 
 import jax
@@ -47,7 +48,9 @@ def make_decode_block(cfg: ModelConfig, block: int, temperature: float,
     Carries (logits, cache, active, remaining); emits per-step tokens and
     the active-at-entry mask so the host can attribute tokens to requests.
     A slot finishes in-scan (budget exhausted or EOS) and stops sampling /
-    bumping lengths for the remaining steps of the block.
+    bumping lengths for the remaining steps of the block.  The logits and
+    cache passed in are donated: the block updates them in place, and the
+    caller takes the returned ones in their stead.
     """
 
     def step(params, carry, key):
@@ -67,7 +70,7 @@ def make_decode_block(cfg: ModelConfig, block: int, temperature: float,
         active = active & ~done
         return (logits, cache, active, remaining), (tok, emitted)
 
-    @jax.jit
+    @partial(jax.jit, donate_argnums=(1, 2))
     def decode_block(params, logits, cache, active, remaining, rng):
         (logits, cache, active, remaining), (toks, emitted) = jax.lax.scan(
             lambda c, k: step(params, c, k),
@@ -79,7 +82,17 @@ def make_decode_block(cfg: ModelConfig, block: int, temperature: float,
 
 
 class ContinuousBatchEngine:
-    """Slot-based serving engine over one model; run() replays a trace."""
+    """Slot-based serving engine over one model; run() replays a trace.
+
+    The engine holds the weights as ``bb.compute_weights`` gives them: every
+    leaf the forward casts at use is cast once, here, so the bucket
+    prefills, the tail advance and the decode block read them in the
+    compute dtype and cast nothing on each call (the operands the casts
+    gave them).  The number and bytes of the leaves so cast go to the
+    global tracer's counters ``serving.precast_leaves`` and
+    ``serving.precast_bytes``.  The float32 master copy stays with whoever
+    owns it (a trainer); the engine keeps no reference to it.
+    """
 
     def __init__(self, cfg: ModelConfig, params, *, n_slots: int,
                  max_context: int, buckets: Sequence[int] = DEFAULT_BUCKETS,
@@ -87,7 +100,13 @@ class ContinuousBatchEngine:
                  eos_id: Optional[int] = None, max_queue: int = 256,
                  seed: int = 0):
         self.cfg = cfg
-        self.params = params
+        self.params = bb.compute_weights(params, cfg)
+        cast = [new for new, old in zip(jax.tree_util.tree_leaves(self.params),
+                                        jax.tree_util.tree_leaves(params))
+                if new.dtype != old.dtype]
+        tr = get_tracer()
+        tr.count("serving.precast_leaves", len(cast))
+        tr.count("serving.precast_bytes", sum(a.nbytes for a in cast))
         self.n_slots = n_slots
         self.max_queue = max_queue
         self.block = decode_block

@@ -124,8 +124,10 @@ class SlotCache:
         # One compiled prefill per bucket; everything else compiles once.
         self._prefill = {b: jax.jit(prefill_one) for b in self.buckets}
         self._advance = jax.jit(advance_one)
-        self._write = jax.jit(_write_slot)
-        self._reset = jax.jit(_reset_slot)
+        # The batch cache and logits are donated: a slot write or reset
+        # updates them in place instead of making a second batch cache.
+        self._write = jax.jit(_write_slot, donate_argnums=(0, 1))
+        self._reset = jax.jit(_reset_slot, donate_argnums=(0, 1))
         self.prefill_tokens = 0  # running count, for prefill tok/s
 
     # -- lifecycle ------------------------------------------------------------
@@ -176,14 +178,14 @@ class SlotCache:
 
     def warmup(self, params) -> None:
         """Compile every bucket prefill + the surgery programs up front so
-        steady-state serving never compiles (the zero-recompile invariant)."""
-        keep_cache, keep_logits, keep_count = (self.cache, self.logits,
-                                               self.prefill_tokens)
+        steady-state serving never compiles (the zero-recompile invariant).
+        The writes update the batch cache in place, so warm-up ends with a
+        fresh one."""
+        keep_count = self.prefill_tokens
         for i, b in enumerate(self.buckets):
             # smallest bucket warms the tail-advance program too (len b+1)
             dummy = np.zeros((b + 1 if i == 0 else b,), np.int32)
             self.write_prefill_at(params, 0, dummy)
         self.reset_slot(0)
-        self.cache, self.logits, self.prefill_tokens = (keep_cache,
-                                                        keep_logits,
-                                                        keep_count)
+        self.reset_all()
+        self.prefill_tokens = keep_count

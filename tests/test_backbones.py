@@ -116,3 +116,51 @@ def test_long_context_skips_documented():
     for a in ARCH_IDS:
         names = [c.name for c in cells(a)]
         assert "train_4k" in names and "decode_32k" in names
+
+
+# Leaves the forward reads in float32, which the compute-dtype tree keeps.
+FLOAT32_READS = {"scale", "norm_scale", "A_log", "dt_bias", "value_head"}
+
+
+@pytest.mark.parametrize("aid", ARCH_IDS)
+def test_compute_weights_keeps_float32_reads_and_is_idempotent(aid, rng):
+    cfg = get_smoke_config(aid)
+    params = bb.init_lm(rng, cfg)
+    once = bb.compute_weights(params, cfg)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(once):
+        want = (jnp.float32 if path[-1].key in FLOAT32_READS
+                else jnp.dtype(cfg.compute_dtype))
+        assert leaf.dtype == want, (jax.tree_util.keystr(path), leaf.dtype)
+    twice = bb.compute_weights(once, cfg)
+    for a, b in zip(jax.tree_util.tree_leaves(once),
+                    jax.tree_util.tree_leaves(twice)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("aid", ARCH_IDS)
+def test_compute_weights_prefill_decode_logits_bitwise(aid, rng):
+    """Prefill, one decode step and their logits, each compiled, read the
+    compute-dtype tree to the same bits as the float32 tree: logits and
+    every cache leaf."""
+    cfg = get_smoke_config(aid)
+    params = bb.init_lm(rng, cfg)
+    tokens = jax.random.randint(rng, (B, 9), 0, cfg.vocab)
+    kw = _extras(cfg, rng)
+
+    @jax.jit
+    def serve(p):
+        cache = bb.init_cache(cfg, B, 16, img_len=cfg.n_img_tokens,
+                              enc_len=cfg.enc_len)
+        h, cache = bb.prefill(p, tokens[:, :8], cfg, cache, **kw)
+        first = bb.lm_logits(p, h, cfg)
+        h, cache2 = bb.decode_step(p, cache, tokens[:, 8], cfg)
+        return first, cache, bb.lm_logits(p, h, cfg), cache2
+
+    want = jax.tree_util.tree_leaves_with_path(serve(params))
+    got = jax.tree_util.tree_leaves(serve(bb.compute_weights(params, cfg)))
+    assert len(want) == len(got)
+    for (path, w), g in zip(want, got):
+        assert w.dtype == g.dtype, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g),
+                                      err_msg=jax.tree_util.keystr(path))

@@ -13,6 +13,7 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
+import jax.extend.core as jex_core
 import pytest
 
 from repro.configs import get_smoke_config
@@ -241,6 +242,144 @@ def test_engine_samples_block_i_with_the_ith_split_of_its_seed():
             engine._decode_block(params, logits, cache, active, remaining, k)
         want += np.asarray(toks)[np.asarray(emitted)].tolist()
     assert req.tokens.tolist() == want and len(want) == 6
+
+
+# -- the engine's weights in the compute dtype ---------------------------------
+
+SERVING_ARCHS = ["glm4-9b", "gemma2-2b", "mamba2-1.3b"]
+
+
+@pytest.mark.parametrize("arch", SERVING_ARCHS)
+def test_engine_serves_the_tokens_of_the_float32_tree(arch):
+    """The engine, holding the compute-dtype tree, serves the tokens its
+    slot cache and decode block serve when driven with the float32 tree."""
+    cfg = get_smoke_config(arch)
+    params = _params(cfg)
+    engine = ContinuousBatchEngine(cfg, params, n_slots=3,
+                                   max_context=MAX_CONTEXT, buckets=(8,),
+                                   decode_block=4)
+    reqs = sorted(poisson_trace(4, 3, 100.0, prompt_len_range=(8, 14),
+                                max_tokens_range=(5, 12), vocab=cfg.vocab),
+                  key=lambda r: r.arrival_s)
+    engine.run(reqs, realtime=False)   # all three admitted to slots 0, 1, 2
+
+    slots = SlotCache(cfg, 3, MAX_CONTEXT, buckets=(8,))
+    for s, r in enumerate(reqs):
+        slots.write_prefill_at(params, s, r.prompt)
+    budgets = [r.max_tokens for r in reqs]
+    toks = _greedy_blocks(cfg, params, slots, [True] * 3, budgets,
+                          n_blocks=-(-max(budgets) // 4))
+    for s, r in enumerate(reqs):
+        np.testing.assert_array_equal(r.tokens, toks[:r.max_tokens, s])
+
+
+def _converted_leaves(jaxpr, taint, dtype):
+    """Roots of the ``convert_element_type`` equations to ``dtype`` in
+    ``jaxpr``, and in the jaxprs inside it, whose operand is a variable of
+    ``taint`` (var -> root), or a slice, reshape or gather of one, also one
+    a nested ``jit`` returns.  Fills ``taint`` with what it derives."""
+    reshapes = {"slice", "dynamic_slice", "squeeze", "reshape",
+                "broadcast_in_dim", "transpose", "gather"}
+    found = set()
+    for eqn in jaxpr.eqns:
+        roots = [None if isinstance(v, jex_core.Literal) else taint.get(v)
+                 for v in eqn.invars]
+        name = eqn.primitive.name
+        if (name == "convert_element_type" and roots[0] is not None
+                and eqn.params["new_dtype"] == dtype):
+            found.add(roots[0])
+        if name in reshapes and roots[0] is not None:
+            taint.update((v, roots[0]) for v in eqn.outvars)
+        for sub in eqn.params.values():
+            if not isinstance(sub, (jex_core.ClosedJaxpr, jex_core.Jaxpr)):
+                continue
+            sub = getattr(sub, "jaxpr", sub)
+            # jit and scan bodies take the equation's operands in order
+            assert len(sub.invars) == len(roots), name
+            sub_taint = {v: r for v, r in zip(sub.invars, roots)
+                         if r is not None}
+            found |= _converted_leaves(sub, sub_taint, dtype)
+            if name == "jit":
+                taint.update((v, sub_taint[o])
+                             for v, o in zip(eqn.outvars, sub.outvars)
+                             if not isinstance(o, jex_core.Literal)
+                             and o in sub_taint)
+    return found
+
+
+@pytest.mark.parametrize("arch", SERVING_ARCHS)
+def test_engine_decode_block_casts_no_precast_leaf(arch):
+    """The decode block's program on the engine's tree casts no leaf to the
+    compute dtype; on the float32 tree it casts exactly the leaves the
+    engine holds in that dtype."""
+    cfg = get_smoke_config(arch)
+    params = _params(cfg)
+    engine = ContinuousBatchEngine(cfg, params, n_slots=2, max_context=24,
+                                   buckets=(8,), decode_block=2)
+    f32 = jax.tree_util.tree_leaves(params)
+    held = jax.tree_util.tree_leaves(engine.params)
+    precast = {i for i, (a, b) in enumerate(zip(f32, held))
+               if a.dtype != b.dtype}
+    assert precast
+
+    def converted(tree):
+        jaxpr = jax.make_jaxpr(engine._decode_block)(
+            tree, engine.slots.logits, engine.slots.cache,
+            jnp.ones((2,), bool), jnp.ones((2,), jnp.int32),
+            jax.random.PRNGKey(0)).jaxpr
+        taint = dict(zip(jaxpr.invars[:len(f32)], range(len(f32))))
+        return _converted_leaves(jaxpr, taint, jnp.dtype(cfg.compute_dtype))
+
+    assert converted(engine.params) == set()
+    assert converted(params) == precast
+
+
+def test_engine_counts_the_leaves_it_holds_in_the_compute_dtype():
+    from repro.telemetry import trace
+
+    cfg = get_smoke_config("mamba2-1.3b")
+    params = _params(cfg)
+    glob = trace.configure(None)
+    engine = ContinuousBatchEngine(cfg, params, n_slots=2, max_context=24,
+                                   buckets=(8,), decode_block=2)
+    precast = [b for a, b in zip(jax.tree_util.tree_leaves(params),
+                                 jax.tree_util.tree_leaves(engine.params))
+               if a.dtype != b.dtype]
+    # tok_embed, lm_head and the SSD block's wz, wx, wB, wC, wdt, conv_w,
+    # out_proj
+    assert len(precast) == 9
+    assert all(a.dtype == jnp.bfloat16 for a in precast)
+    assert glob.counters["serving.precast_leaves"] == 9
+    assert glob.counters["serving.precast_bytes"] == sum(
+        2 * a.size for a in precast)
+
+
+def test_slot_write_and_decode_block_update_the_batch_cache_in_place():
+    """The slot write, the slot reset and the decode block take the batch
+    cache and logits as donations, so no second batch cache is made."""
+    cfg = get_smoke_config("mamba2-1.3b")
+    engine = ContinuousBatchEngine(cfg, _params(cfg), n_slots=2,
+                                   max_context=24, buckets=(8,),
+                                   decode_block=2)
+    slots = engine.slots
+
+    def donated(call):
+        before = jax.tree_util.tree_leaves((slots.cache, slots.logits))
+        call()
+        assert all(a.is_deleted() for a in before)
+
+    donated(lambda: slots.write_prefill_at(engine.params, 0,
+                                           np.arange(1, 10, dtype=np.int32)))
+    donated(lambda: slots.reset_slot(1))
+
+    def decode():
+        out = engine._decode_block(engine.params, slots.logits, slots.cache,
+                                   jnp.ones((2,), bool),
+                                   jnp.full((2,), 2, jnp.int32),
+                                   jax.random.PRNGKey(0))
+        slots.logits, slots.cache = out[0], out[1]
+    donated(decode)
+    assert slots.lengths().tolist() == [11, 2]
 
 
 # -- the engine's spans, counters and samples ---------------------------------
